@@ -83,24 +83,12 @@ def _time(fn, repeats: int = 5) -> float:
     return best
 
 
-def _xla_cost(compiled) -> dict:
-    """``Compiled.cost_analysis()`` across jax versions: newer returns a
-    dict, older a list with one dict per partition, some backends raise."""
-    try:
-        ca = compiled.cost_analysis()
-    except Exception:
-        return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca) if isinstance(ca, dict) else {}
-
-
 def dispatch_cost(fn, *args) -> dict[str, float]:
     """Compile ``fn(*args)`` and report its static cost model: HLO dot
     FLOPs (launch.hlo_analysis, loop-multiplied), XLA's own flops/bytes
     estimate, collective bytes, and the roofline time bounds those imply."""
     compiled = jax.jit(fn).lower(*args).compile()
-    cost = _xla_cost(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     dot = analyze_dot_flops(hlo)
     coll = analyze_collectives(hlo)

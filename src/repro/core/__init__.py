@@ -15,12 +15,3 @@
   mesh-native since §10: sessions shard tenants over an explicit device
   mesh and restart from event-boundary checkpoints.
 """
-
-import jax
-
-
-def donate_argnums(*argnums: int) -> tuple[int, ...]:
-    """Scan-carry donation policy for the fused epoch loops (DESIGN.md §2):
-    donate off-CPU, where it enables in-place cache/optimizer updates; the
-    CPU backend does not implement donation and would only warn."""
-    return argnums if jax.default_backend() != "cpu" else ()

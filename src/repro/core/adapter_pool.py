@@ -40,19 +40,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import donate_argnums
 from repro.core.lm_skiplora import quantize_int8
 from repro.kernels.skip_lora import quant as q4
 from repro.models.config import ModelConfig
 
 Params = Any
 
-#: In-place single-slot write: the pool array is donated (off-CPU), so a
+#: In-place single-slot write: the pool array is donated, so a
 #: registration costs one O(L*D*R) slot write, never a full-pool copy.
 #: ``slot`` rides as a traced scalar so every slot shares one trace.
 _set_slot = jax.jit(
     lambda arr, slot, val: arr.at[slot].set(val),
-    donate_argnums=donate_argnums(0),
+    donate_argnums=(0,),
 )
 
 #: pinned all-zeros slot: rows with no registered adapter (base model).
@@ -357,7 +356,7 @@ class AdapterPool:
         evicts the least-recently-served tenant. ``meta`` optionally stamps
         the new version's {"step", "eval_loss"}.
 
-        Off-CPU the slot write donates the pool buffers (an in-place
+        The slot write donates the pool buffers (an in-place
         O(L*D*R) write, never a full-pool copy) — any dict previously
         returned by ``pools()`` is invalidated; re-fetch it after
         registration and never register mid-flight of a computation that
@@ -524,7 +523,7 @@ class AdapterPool:
         are handed over *raw* (dequant lives in the kernel; ``code`` is the
         16-entry codebook that distinguishes int4 from nf4).
         The dict is a snapshot of the live buffers: ``register`` donates
-        them off-CPU, so re-fetch after any registration (see ``register``).
+        them, so re-fetch after any registration (see ``register``).
         """
         if self.compress in q4.Q4_KINDS:
             return {
@@ -600,7 +599,10 @@ class AdapterPool:
             raise ValueError(f"pool arrays {set(data)} != expected {want}")
         for name, arr in data.items():
             cur = self.pools()[name]
-            arr = jnp.asarray(arr, cur.dtype)
+            # A copy, never the caller's buffer: slot writes donate the pool
+            # arrays, and ``arrays`` may be another live pool's
+            # ``state_arrays()``.
+            arr = jnp.array(arr, cur.dtype)
             if arr.shape != cur.shape:
                 raise ValueError(
                     f"pool array {name}: {arr.shape} != {cur.shape}"

@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import donate_argnums
 from repro.core import methods as M
 from repro.core import skip_cache as C
 from repro.models.mlp import MLPConfig, accuracy, cross_entropy
@@ -85,7 +84,7 @@ def make_epoch_fn(method: str, cfg: MLPConfig) -> Callable:
 
         return jax.lax.scan(body, trainable, idx_mat)
 
-    return jax.jit(epoch, donate_argnums=donate_argnums(0))
+    return jax.jit(epoch, donate_argnums=(0,))
 
 
 def finetune(
@@ -242,10 +241,9 @@ def make_skip2_epoch_fns(cfg: MLPConfig, *, donate: bool = True) -> tuple[Callab
 
         return jax.lax.scan(body, trainable, idx_mat)
 
-    d = donate_argnums if donate else (lambda *a: ())
     return (
-        jax.jit(populate_epoch, donate_argnums=d(0, 2)),
-        jax.jit(cached_epoch, donate_argnums=d(0)),
+        jax.jit(populate_epoch, donate_argnums=(0, 2) if donate else ()),
+        jax.jit(cached_epoch, donate_argnums=(0,) if donate else ()),
     )
 
 

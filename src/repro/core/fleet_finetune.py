@@ -48,7 +48,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import donate_argnums
 from repro.core import lm_skiplora as SL
 from repro.core.skip_cache import SkipCache, cache_read, cache_write
 from repro.kernels.skip_lora.ops import (
@@ -303,8 +302,7 @@ def make_fleet_cached_epoch(
 
     if not jit:
         return epoch
-    d = donate_argnums if donate else (lambda *a: ())
-    return jax.jit(epoch, donate_argnums=d(1, 2))
+    return jax.jit(epoch, donate_argnums=(1, 2) if donate else ())
 
 
 def make_fleet_eval_loss(
@@ -382,8 +380,7 @@ def make_fleet_cached_epoch_eval(
         post = held_out(stacked) if eval_post else None
         return stacked, opt_state, losses, pre, post
 
-    d = donate_argnums if donate else (lambda *a: ())
-    return jax.jit(epoch, donate_argnums=d(1, 2))
+    return jax.jit(epoch, donate_argnums=(1, 2) if donate else ())
 
 
 def make_fleet_populate_epoch(
@@ -442,8 +439,7 @@ def make_fleet_populate_epoch(
 
     if not jit:
         return epoch
-    d = donate_argnums if donate else (lambda *a: ())
-    return jax.jit(epoch, donate_argnums=d(1, 2, 3))
+    return jax.jit(epoch, donate_argnums=(1, 2, 3) if donate else ())
 
 
 def fleet_cached_epoch_via_engine(

@@ -48,7 +48,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import donate_argnums
 from repro.core import runtime as RT
 from repro.kernels.flash_attn import paged
 
@@ -211,7 +210,7 @@ class KVBlockPool:
         """Copy live cache row ``row``'s prompt blocks into the pool:
         block ``slots[j]`` of the row (token span [slots[j]*block,
         (slots[j]+1)*block)) lands in pool block ``ids[j]``. One fused
-        dispatch per (m, geometry); the pool tree is donated off-CPU."""
+        dispatch per (m, geometry); the pool tree is donated."""
         ids = np.asarray(ids, np.int32).reshape(-1)
         slots = np.asarray(slots, np.int32).reshape(-1)
         if ids.size == 0:
@@ -244,7 +243,7 @@ class KVBlockPool:
 
                 return jax.tree.map(leaf, data, caches)
 
-            return jax.jit(f, donate_argnums=donate_argnums(0))
+            return jax.jit(f, donate_argnums=(0,))
 
         return make
 
@@ -275,7 +274,7 @@ class KVBlockPool:
                 data,
             )
 
-        return jax.jit(f, donate_argnums=donate_argnums(0))
+        return jax.jit(f, donate_argnums=(0,))
 
     # -- checkpoint ----------------------------------------------------------
 
@@ -318,8 +317,10 @@ class KVBlockPool:
             for j in range(len(self.data["remainder"]))
         ]
         data = {"periods": periods, "remainder": remainder}
+        # Copies, never the caller's buffers: publish/copy donate the data
+        # plane, and ``arrays`` may be another live pool's ``state_arrays()``.
         data = jax.tree.map(
-            lambda ref, x: jnp.asarray(x, ref.dtype), self.data, data
+            lambda ref, x: jnp.array(x, ref.dtype), self.data, data
         )
         # Same commitment rule as construction: restored data must land on
         # a concrete device so post-restore publishes reuse the jit cache.

@@ -34,7 +34,6 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core import donate_argnums
 from repro.core.skip_cache import SkipCache, cache_read, cache_write, init_cache
 from repro.models.config import ModelConfig
 from repro.models.lm import lm_forward, lm_loss
@@ -348,7 +347,6 @@ def make_populate_epoch(cfg: ModelConfig, sl: SkipLoRAConfig, optimizer, *,
     donated so the cache updates in place across scan iterations —
     ``donate=False`` for callers that reuse the carry arrays afterwards."""
     step = make_populate_step(cfg, sl, optimizer)
-    d = donate_argnums if donate else (lambda *a: ())
 
     def epoch(params, trainable, static, opt_state, cache, tokens, labels, idx_mat):
         def body(carry, idx):
@@ -362,7 +360,7 @@ def make_populate_epoch(cfg: ModelConfig, sl: SkipLoRAConfig, optimizer, *,
         )
         return trainable, opt_state, cache, losses
 
-    return jax.jit(epoch, donate_argnums=d(1, 3, 4))
+    return jax.jit(epoch, donate_argnums=(1, 3, 4) if donate else ())
 
 
 def make_cached_epoch(cfg: ModelConfig, sl: SkipLoRAConfig, optimizer, *,
@@ -370,7 +368,6 @@ def make_cached_epoch(cfg: ModelConfig, sl: SkipLoRAConfig, optimizer, *,
     """Whole cached epoch as one lax.scan dispatch: cache gathers + adapter
     steps only, zero backbone compute and zero Python in the loop."""
     step = make_cached_step(cfg, sl, optimizer)
-    d = donate_argnums if donate else (lambda *a: ())
 
     def epoch(params, trainable, static, opt_state, cache, idx_mat):
         def body(carry, idx):
@@ -383,4 +380,4 @@ def make_cached_epoch(cfg: ModelConfig, sl: SkipLoRAConfig, optimizer, *,
         )
         return trainable, opt_state, losses
 
-    return jax.jit(epoch, donate_argnums=d(1, 3))
+    return jax.jit(epoch, donate_argnums=(1, 3) if donate else ())

@@ -70,7 +70,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import donate_argnums
 from repro.core import batch_plan
 from repro.core import fleet_finetune as FF
 from repro.core import lm_skiplora as SL
@@ -192,8 +191,7 @@ def _decode_scan_fn(cfg, use_kernel: bool = True, fuse_skip: bool = False,
                     use_kernel=use_kernel, fuse_skip=fuse_skip, unroll=unroll,
                 )
 
-        # Donate the KV caches: the scan's carry updates them in place
-        # (off-CPU; the CPU backend has no donation and would only warn).
+        # Donate the KV caches: the scan's carry updates them in place.
         # ``temperature`` (arg 9) is deliberately NOT static: baking it into
         # the trace cache meant one full decode recompile per distinct
         # sampling temperature under live traffic. It is traced now (the
@@ -202,7 +200,7 @@ def _decode_scan_fn(cfg, use_kernel: bool = True, fuse_skip: bool = False,
         return jax.jit(
             f,
             static_argnums=(8, 10),
-            donate_argnums=donate_argnums(3),
+            donate_argnums=(3,),
         )
 
     return _cached_fn("decode_scan", cfg, make, (use_kernel, fuse_skip, scope))
@@ -910,11 +908,7 @@ class SessionRuntime:
         if st is None:
             st = self._add_tenant(tenant)
         s = self._shard_of_partition(st.partition)
-        who = [tenant if self.pool.has(tenant) else None] * b
-        idx = self.pool.lookup_local(s, who)
-        logits, acts, y_base = _ingest_fn(
-            self.cfg, self.use_kernel, self._scope[s]
-        )(self._shard_params[s], tokens, self.pool.shard_pools(s), idx)
+        logits, acts, y_base = self._populate(s, tenant, tokens)
         values = SL._encode_acts(acts, None, self.sl)
         values["y_base"] = y_base
         values["labels"] = labels
@@ -926,6 +920,25 @@ class SessionRuntime:
         st.n_ingested += b
         self.counters["ingest/rows"] += b
         return logits
+
+    def score(self, tenant, tokens: jax.Array) -> jax.Array:
+        """Last-position logits (B, 1, V) of ``tokens`` under ``tenant``'s
+        served adapters (``None`` or a tenant without a slot: the base
+        model) — the populate forward ``ingest`` runs, with no cache write
+        and no tenant registration."""
+        s = self.pool.shard_of(tenant) if (
+            tenant is not None and self.pool.has(tenant)
+        ) else 0
+        return self._populate(s, tenant, tokens)[0]
+
+    def _populate(self, s: int, tenant, tokens: jax.Array):
+        """(logits, acts, y_base) of the shard-``s`` populate forward, each
+        row under ``tenant``'s pool slot (the zero slot when it has none)."""
+        who = [tenant if self.pool.has(tenant) else None] * tokens.shape[0]
+        idx = self.pool.lookup_local(s, who)
+        return _ingest_fn(self.cfg, self.use_kernel, self._scope[s])(
+            self._shard_params[s], tokens, self.pool.shard_pools(s), idx
+        )
 
     def adapt(
         self,

@@ -63,7 +63,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import batch_plan, donate_argnums
+from repro.core import batch_plan
 from repro.core import runtime as RT
 from repro.models.blocks import ATTN_KINDS
 from repro.models.lm import (
@@ -177,7 +177,7 @@ def _sched_step_fn(cfg, use_kernel: bool, chunk: int, max_seq: int,
                     tok, pos, active, temps, key, max_seq,
                 )
 
-        return jax.jit(f, donate_argnums=donate_argnums(3))
+        return jax.jit(f, donate_argnums=(3,))
 
     return RT.compiled(
         ("sched_step", cfg, use_kernel, chunk, max_seq, fuse, scope), make
@@ -215,7 +215,7 @@ def _sched_admit_fn(cfg, use_kernel: bool, chunk: int, max_seq: int,
                 )
                 return caches, tok, pos, toks, tok0
 
-        return jax.jit(f, donate_argnums=donate_argnums(7))
+        return jax.jit(f, donate_argnums=(7,))
 
     return RT.compiled(
         ("sched_admit", cfg, use_kernel, chunk, max_seq, bucket, prompt, fuse,
@@ -263,7 +263,7 @@ def _sched_admit_pipe_fn(cfg, use_kernel: bool, chunk: int, max_seq: int,
                 )
                 return caches, tok, pos, toks, tok0
 
-        return jax.jit(f, donate_argnums=donate_argnums(9))
+        return jax.jit(f, donate_argnums=(9,))
 
     return RT.compiled(
         ("sched_admit_pipe", cfg, use_kernel, chunk, max_seq, bucket, prompt,
@@ -329,7 +329,7 @@ def _sched_admit_reuse_fn(cfg, use_kernel: bool, chunk: int, max_seq: int,
                 )
                 return caches, tok, pos, toks, tok0
 
-        return jax.jit(f, donate_argnums=donate_argnums(10))
+        return jax.jit(f, donate_argnums=(10,))
 
     return RT.compiled(
         ("sched_admit_reuse", cfg, use_kernel, chunk, max_seq, bucket, prompt,

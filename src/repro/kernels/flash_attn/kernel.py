@@ -25,9 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams in newer releases.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 BQ = 128
 BK = 128
 NEG_INF = -2.0e38
@@ -125,7 +122,7 @@ def flash_attention_fwd(
             pltpu.VMEM((BQ,), jnp.float32),
             pltpu.VMEM((BQ,), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

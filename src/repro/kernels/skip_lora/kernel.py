@@ -19,6 +19,12 @@ rows are pre-grouped by adapter so each tile gathers exactly one (A, B)
 layer block from the pool per grid step (BGMV-style). The int8 grouped
 variant keeps the pool int8 in HBM and dequantises gathered blocks in VMEM.
 
+Scales enter every kernel as columns — pool scales (N, L, rows, 1), cache
+row scales (L, M, 1) — reshaped by the wrappers below. Mosaic tiles a
+block's last two dims by (8, 128) unless they equal the array's, so a
+(1, 1, rows) block of an (N, L, rows) array is refused while (1, 1, rows, 1)
+is not, and a column broadcasts over the payload's lanes with no relayout.
+
 VMEM budget per step (bf16, TM=128, D=8192 worst case among assigned archs):
 x tile 2 MB + fp32 out tile 4 MB + A/B/z < 1.5 MB << 16 MB/core.
 """
@@ -33,9 +39,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.skip_lora import quant as _Q
-
-# jax renamed TPUCompilerParams -> CompilerParams in newer releases.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 #: Default row-tile size (MXU-aligned). Every kernel below takes ``tm`` as a
 #: static parameter; this constant is only the untuned fallback — the
@@ -114,7 +117,7 @@ def skip_lora_fwd(
         ],
         out_specs=pl.BlockSpec((tm, d), lambda mi, li: (mi, 0)),
         out_shape=jax.ShapeDtypeStruct((m, d), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -171,7 +174,7 @@ def skip_lora_bwd(
             jax.ShapeDtypeStruct((lnum, d, r), jnp.float32),
             jax.ShapeDtypeStruct((lnum, r, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -236,7 +239,7 @@ def skip_lora_grouped_fwd(
         functools.partial(_grouped_fwd_kernel, l_axis),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, d), jnp.float32),
-        compiler_params=_CompilerParams(dimension_semantics=semantics),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
         interpret=interpret,
     )(tile_adapter, x, a_pool, b_pool)
     return out.astype(x.dtype)
@@ -251,8 +254,8 @@ def _grouped_fwd_int8_kernel(l_axis, g_ref, x_ref, qa_ref, sa_ref, qb_ref, sb_re
         o_ref[...] = jnp.zeros_like(o_ref)
 
     x = x_ref[0]                                             # (TM, D)
-    a = (qa_ref[0, 0].astype(jnp.float32) * sa_ref[0, 0][:, None]).astype(x.dtype)
-    b = (qb_ref[0, 0].astype(jnp.float32) * sb_ref[0, 0][:, None]).astype(x.dtype)
+    a = (qa_ref[0, 0].astype(jnp.float32) * sa_ref[0, 0]).astype(x.dtype)
+    b = (qb_ref[0, 0].astype(jnp.float32) * sb_ref[0, 0]).astype(x.dtype)
     z = jnp.dot(x, a, preferred_element_type=jnp.float32).astype(x.dtype)
     o_ref[...] += jnp.dot(z, b, preferred_element_type=jnp.float32)
 
@@ -276,6 +279,7 @@ def skip_lora_grouped_fwd_int8(
     never materialised outside the kernel."""
     lnum, m, d = x.shape
     n, _, _, r = qa.shape
+    sa, sb = sa[..., None], sb[..., None]  # columns: see module docstring
     assert m % tm == 0
     grid, wrap, l_axis, semantics = _grouped_grid(grid_order, m // tm, lnum)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -284,9 +288,9 @@ def skip_lora_grouped_fwd_int8(
         in_specs=[
             pl.BlockSpec((1, tm, d), wrap(lambda mi, li, g: (li, mi, 0))),
             pl.BlockSpec((1, 1, d, r), wrap(lambda mi, li, g: (g[mi], li, 0, 0))),
-            pl.BlockSpec((1, 1, d), wrap(lambda mi, li, g: (g[mi], li, 0))),
+            pl.BlockSpec((1, 1, d, 1), wrap(lambda mi, li, g: (g[mi], li, 0, 0))),
             pl.BlockSpec((1, 1, r, d), wrap(lambda mi, li, g: (g[mi], li, 0, 0))),
-            pl.BlockSpec((1, 1, r), wrap(lambda mi, li, g: (g[mi], li, 0))),
+            pl.BlockSpec((1, 1, r, 1), wrap(lambda mi, li, g: (g[mi], li, 0, 0))),
         ],
         out_specs=pl.BlockSpec((tm, d), wrap(lambda mi, li, g: (mi, 0))),
     )
@@ -294,10 +298,19 @@ def skip_lora_grouped_fwd_int8(
         functools.partial(_grouped_fwd_int8_kernel, l_axis),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, d), jnp.float32),
-        compiler_params=_CompilerParams(dimension_semantics=semantics),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
         interpret=interpret,
     )(tile_adapter, x, qa, sa, qb, sb)
     return out.astype(x.dtype)
+
+
+def _codebook_lookup(code_ref, nib: jax.Array) -> jax.Array:
+    """``code[nib]`` as a 16-way select over the SMEM codebook: Mosaic
+    lowers only 2-D gathers, and a select picks each level exactly."""
+    out = jnp.zeros(nib.shape, jnp.float32)
+    for k in range(16):
+        out = jnp.where(nib == k, code_ref[0, k], out)
+    return out
 
 
 def _grouped_fwd_q4_kernel(
@@ -311,19 +324,13 @@ def _grouped_fwd_q4_kernel(
         o_ref[...] = jnp.zeros_like(o_ref)
 
     x = x_ref[0]                                             # (TM, D)
-    code = code_ref[0]                                       # (16,) fp32
     # Unpack nibbles + codebook-dequant the gathered blocks in VMEM: the
-    # pool payload crosses HBM packed (two 4-bit indices per byte).
-    a_nib = _Q.unpack_nibbles(qa_ref[0, 0])                  # (D, R)
-    b_nib = _Q.unpack_nibbles(qb_ref[0, 0])                  # (R, D)
-    a = (
-        jnp.take(code, a_nib.astype(jnp.int32), axis=0)
-        * sa_ref[0, 0][:, None]
-    ).astype(x.dtype)
-    b = (
-        jnp.take(code, b_nib.astype(jnp.int32), axis=0)
-        * sb_ref[0, 0][:, None]
-    ).astype(x.dtype)
+    # pool payload crosses HBM packed (two 4-bit indices per byte). The
+    # unpack runs on int32: Mosaic cannot relayout a uint8 interleave.
+    a_nib = _Q.unpack_nibbles(qa_ref[0, 0].astype(jnp.int32))  # (D, R)
+    b_nib = _Q.unpack_nibbles(qb_ref[0, 0].astype(jnp.int32))  # (R, D)
+    a = (_codebook_lookup(code_ref, a_nib) * sa_ref[0, 0]).astype(x.dtype)
+    b = (_codebook_lookup(code_ref, b_nib) * sb_ref[0, 0]).astype(x.dtype)
     z = jnp.dot(x, a, preferred_element_type=jnp.float32).astype(x.dtype)
     o_ref[...] += jnp.dot(z, b, preferred_element_type=jnp.float32)
 
@@ -349,6 +356,7 @@ def skip_lora_grouped_fwd_q4(
     lnum, m, d = x.shape
     n, _, _, rp = qa.shape
     r = 2 * rp
+    sa, sb = sa[..., None], sb[..., None]  # columns: see module docstring
     assert m % tm == 0
     grid, wrap, l_axis, semantics = _grouped_grid(grid_order, m // tm, lnum)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -357,10 +365,10 @@ def skip_lora_grouped_fwd_q4(
         in_specs=[
             pl.BlockSpec((1, tm, d), wrap(lambda mi, li, g: (li, mi, 0))),
             pl.BlockSpec((1, 1, d, rp), wrap(lambda mi, li, g: (g[mi], li, 0, 0))),
-            pl.BlockSpec((1, 1, d), wrap(lambda mi, li, g: (g[mi], li, 0))),
+            pl.BlockSpec((1, 1, d, 1), wrap(lambda mi, li, g: (g[mi], li, 0, 0))),
             pl.BlockSpec((1, 1, r, d // 2), wrap(lambda mi, li, g: (g[mi], li, 0, 0))),
-            pl.BlockSpec((1, 1, r), wrap(lambda mi, li, g: (g[mi], li, 0))),
-            pl.BlockSpec((1, 16), wrap(lambda mi, li, g: (0, 0))),
+            pl.BlockSpec((1, 1, r, 1), wrap(lambda mi, li, g: (g[mi], li, 0, 0))),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((tm, d), wrap(lambda mi, li, g: (mi, 0))),
     )
@@ -368,7 +376,7 @@ def skip_lora_grouped_fwd_q4(
         functools.partial(_grouped_fwd_q4_kernel, l_axis),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, d), jnp.float32),
-        compiler_params=_CompilerParams(dimension_semantics=semantics),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
         interpret=interpret,
     )(tile_adapter, x, qa, sa, qb, sb, code)
     return out.astype(x.dtype)
@@ -383,7 +391,7 @@ def _grouped_fwd_actint8_kernel(g_ref, q_ref, s_ref, a_ref, b_ref, o_ref):
         o_ref[...] = jnp.zeros_like(o_ref)
 
     q = q_ref[0].astype(jnp.float32)              # (TM, D)
-    s = s_ref[0][:, None]                         # (TM, 1) fp32
+    s = s_ref[0]                                  # (TM, 1) fp32
     x = (q * s).astype(jnp.bfloat16)
     a = a_ref[0, 0].astype(jnp.bfloat16)          # (D, R) gathered from pool
     b = b_ref[0, 0].astype(jnp.bfloat16)          # (R, D)
@@ -416,7 +424,7 @@ def skip_lora_grouped_fwd_actint8(
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, tm, d), lambda mi, li, g: (li, mi, 0)),
-            pl.BlockSpec((1, tm), lambda mi, li, g: (li, mi)),
+            pl.BlockSpec((1, tm, 1), lambda mi, li, g: (li, mi, 0)),
             pl.BlockSpec((1, 1, d, r), lambda mi, li, g: (g[mi], li, 0, 0)),
             pl.BlockSpec((1, 1, r, d), lambda mi, li, g: (g[mi], li, 0, 0)),
         ],
@@ -426,11 +434,11 @@ def skip_lora_grouped_fwd_actint8(
         _grouped_fwd_actint8_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, d), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(tile_adapter, q, scale, a_pool, b_pool)
+    )(tile_adapter, q, scale[..., None], a_pool, b_pool)
     return out.astype(jnp.bfloat16)
 
 
@@ -508,7 +516,7 @@ def skip_lora_grouped_bwd(
             jax.ShapeDtypeStruct((n, lnum, d, r), jnp.float32),
             jax.ShapeDtypeStruct((n, lnum, r, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")
         ),
         interpret=interpret,
@@ -524,7 +532,7 @@ def _fwd_int8_kernel(q_ref, s_ref, a_ref, b_ref, o_ref):
         o_ref[...] = jnp.zeros_like(o_ref)
 
     q = q_ref[0].astype(jnp.float32)          # (TM, D)
-    s = s_ref[0][:, None]                     # (TM, 1) fp32
+    s = s_ref[0]                              # (TM, 1) fp32
     x = (q * s).astype(jnp.bfloat16)
     a = a_ref[0].astype(jnp.bfloat16)
     b = b_ref[0].astype(jnp.bfloat16)
@@ -546,15 +554,15 @@ def skip_lora_fwd_int8(
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, tm, d), lambda mi, li: (li, mi, 0)),
-            pl.BlockSpec((1, tm), lambda mi, li: (li, mi)),
+            pl.BlockSpec((1, tm, 1), lambda mi, li: (li, mi, 0)),
             pl.BlockSpec((1, d, r), lambda mi, li: (li, 0, 0)),
             pl.BlockSpec((1, r, d), lambda mi, li: (li, 0, 0)),
         ],
         out_specs=pl.BlockSpec((tm, d), lambda mi, li: (mi, 0)),
         out_shape=jax.ShapeDtypeStruct((m, d), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(q, scale, a, b)
+    )(q, scale[..., None], a, b)
     return out.astype(jnp.bfloat16)

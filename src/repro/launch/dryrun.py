@@ -181,4 +181,8 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch import enable_compile_cache
+
+    # Every cell's program spans the forced production mesh.
+    enable_compile_cache(jax.device_count())
     main()

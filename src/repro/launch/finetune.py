@@ -166,4 +166,7 @@ def _legacy_freeze_a(args, cfg, sl, params, tokens, labels) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -61,8 +61,13 @@ def _parse_args(argv=None) -> argparse.Namespace:
 
 def main(argv=None) -> dict:
     args = _parse_args(argv)
-    if args.devices > 1 and "--xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
-        # Must land before the first jax import (same trick as dryrun.py).
+    if (
+        args.devices > 1
+        and os.environ.get("JAX_PLATFORMS") == "cpu"
+        and "--xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", "")
+    ):
+        # Host devices are a CPU rehearsal only: a run that finds no chip
+        # must never pass on them. Must land before JAX starts its backend.
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={args.devices} "
             + os.environ.get("XLA_FLAGS", "")
@@ -79,9 +84,8 @@ def main(argv=None) -> dict:
         )
     if len(jax.devices()) < args.devices:
         raise SystemExit(
-            f"need {args.devices} devices, have {len(jax.devices())} "
-            "(set XLA_FLAGS=--xla_force_host_platform_device_count before jax "
-            "imports, or let this CLI do it by running it first)"
+            f"need {args.devices} devices, have {len(jax.devices())} (on the "
+            "CPU, run with JAX_PLATFORMS=cpu to force host devices)"
         )
 
     cfg = get_config(args.arch)
@@ -191,4 +195,7 @@ def _runtime_main(args, cfg, sl, params, tokens, labels, bpt) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -165,4 +165,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -67,9 +67,7 @@ def make_scan_chunk(cfg, opt):
         )
         return params, opt_state, losses
 
-    from repro.core import donate_argnums
-
-    return jax.jit(run_chunk, donate_argnums=donate_argnums(0, 1))
+    return jax.jit(run_chunk, donate_argnums=(0, 1))
 
 
 def main() -> None:
@@ -163,4 +161,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch import enable_compile_cache
+
+    enable_compile_cache()
     main()

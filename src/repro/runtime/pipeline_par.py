@@ -37,16 +37,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.runtime.sharding import suspend_scope
 
-# jax promoted shard_map out of experimental (and renamed check_rep ->
-# check_vma) in newer releases; support both.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _CHECK_KW = "check_vma"
-else:  # jax < 0.6
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"
-
 Params = Any
 
 
@@ -158,12 +148,12 @@ def pipeline_apply(
         return outs
 
     spec_params = jax.tree.map(lambda _: P(axis), stage_params)
-    fn = _shard_map(
+    fn = jax.shard_map(
         per_stage,
         mesh=mesh,
         in_specs=(spec_params, P(axis), P()),
         out_specs=P(),
-        **{_CHECK_KW: False},
+        check_vma=False,
     )
     # The stage body is manual SPMD over ``axis``: any ambient ShardScope's
     # auto-constraints would name an axis shard_map has claimed as manual.
@@ -283,12 +273,12 @@ def pipeline_prefill(
         return y, sk, caches
 
     spec_blocks = jax.tree.map(lambda _: P(axis), stage_blocks)
-    fn = _shard_map(
+    fn = jax.shard_map(
         per_stage,
         mesh=mesh,
         in_specs=(spec_blocks, P(axis), P(axis), P(axis), P(), P(), P()),
         out_specs=(P(), P(), P(axis)),   # specs broadcast over output pytrees
-        **{_CHECK_KW: False},
+        check_vma=False,
     )
     # Manual SPMD region: suspend any ambient ShardScope so the blocks'
     # auto-constraints (which name this same axis) don't trace inside it.

@@ -23,7 +23,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 Params = Any
 
@@ -42,18 +42,21 @@ def make_mesh(
     case); an explicit list pins the grid to those devices — the elastic
     path, where a restart rebuilds the mesh from whatever survived. This is
     the single mesh constructor behind ``launch.mesh``, the fleet/session
-    launchers, and ``fault.elastic_remesh``.
+    launchers, and ``fault.elastic_remesh``. Every axis is ``Auto`` on both
+    branches (``jax.make_mesh`` alone would make them ``Explicit``), so a
+    mesh's sharding semantics never depend on which caller built it.
     """
     shape, axes = tuple(int(s) for s in shape), tuple(axes)
     if len(shape) != len(axes):
         raise ValueError(f"mesh shape {shape} != axes {axes}")
+    auto = (AxisType.Auto,) * len(axes)
     if devices is None:
-        return jax.make_mesh(shape, axes)
+        return jax.make_mesh(shape, axes, axis_types=auto)
     n = int(np.prod(shape))
     devices = list(devices)
     if len(devices) < n:
         raise ValueError(f"mesh {shape} needs {n} devices, have {len(devices)}")
-    return Mesh(np.asarray(devices[:n]).reshape(shape), axes)
+    return Mesh(np.asarray(devices[:n]).reshape(shape), axes, axis_types=auto)
 
 
 def session_mesh_layout(mesh: Mesh) -> tuple[int, int, list[list]]:

@@ -297,6 +297,25 @@ class TestPoolVersioning:
                 slot_payload_np(twin, "u")[n], arr, err_msg=n
             )
 
+    @pytest.mark.parametrize("compress", COMPRESS)
+    def test_loaded_twin_survives_source_writes(self, cfg, compress):
+        # Slot writes donate the pool buffers, so ``load_state`` must copy
+        # what it is given: writing to the source pool afterwards may
+        # neither delete nor change the twin's arrays.
+        pool = AdapterPool(3, cfg, rank=4, compress=compress, history=2)
+        pool.register("u", make_adapters(cfg, 1), meta={"step": 2})
+        twin = AdapterPool(3, cfg, rank=4, compress=compress, history=2)
+        twin.load_state(
+            pool.state_arrays(), json.loads(json.dumps(pool.slot_table()))
+        )
+        before = slot_payload_np(twin, "u")
+        pool.register("u", make_adapters(cfg, 2), meta={"step": 4})
+        pool.register("v", make_adapters(cfg, 3), meta={"step": 2})
+        for n, arr in slot_payload_np(twin, "u").items():
+            np.testing.assert_array_equal(arr, before[n], err_msg=n)
+        twin.register("w", make_adapters(cfg, 4))   # its own live buffers
+        assert twin.has("w") and not pool.has("w")
+
 
 class TestGatedRuntime:
     def _adapted(self, cfg, params, control, **kw):

@@ -143,6 +143,22 @@ class TestPoolDataPlane:
                 np.asarray(jnp.take(leaf, dst, axis=-4)),
             )
 
+    def test_loaded_twin_survives_source_publish(self, cfg):
+        # publish donates the data plane, so ``load_state`` must copy what
+        # it is given: a later publish into the source pool may neither
+        # delete nor change the twin's blocks.
+        pool = KVBlockPool(cfg, n_blocks=4, block=4)
+        ids = pool.alloc(1)
+        pool.publish(fill_random(init_serve_caches(cfg, 1, 4), seed=4), 0,
+                     ids, [0])
+        twin = KVBlockPool(cfg, n_blocks=4, block=4)
+        twin.load_state(pool.state_arrays(), pool.state_meta())
+        before = [np.asarray(x) for x in jax.tree.leaves(twin.data)]
+        pool.publish(fill_random(init_serve_caches(cfg, 1, 4), seed=5), 0,
+                     ids, [0])
+        for got, want in zip(jax.tree.leaves(twin.data), before):
+            np.testing.assert_array_equal(np.asarray(got), want)
+
     def test_load_state_rejects_geometry_mismatch(self, cfg):
         pool = KVBlockPool(cfg, n_blocks=4, block=4)
         other = KVBlockPool(cfg, n_blocks=2, block=4)

@@ -181,7 +181,7 @@ class TestShardingSpecsMultiDevice:
     def test_param_specs_subprocess(self):
         env = dict(os.environ)
         env["PYTHONPATH"] = "src"
-        env.pop("JAX_PLATFORMS", None)
+        env["JAX_PLATFORMS"] = "cpu"  # forced host devices, never a chip
         res = subprocess.run(
             [sys.executable, "-c", SPEC_PROG], capture_output=True, text=True,
             env=env, timeout=600,

@@ -59,8 +59,9 @@ SUBPROCESS_PROG = textwrap.dedent(
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax, jax.numpy as jnp, numpy as np
     from repro.runtime.pipeline_par import pipeline_apply, split_stages
+    from repro.runtime.sharding import make_mesh
 
-    mesh = jax.make_mesh((4,), ("pod",))
+    mesh = make_mesh((4,), ("pod",))
     L, D = 8, 16
     key = jax.random.key(0)
     layers = [
@@ -115,7 +116,7 @@ class TestPipelineMultiDevice:
     def test_pipeline_matches_sequential_subprocess(self):
         env = dict(os.environ)
         env["PYTHONPATH"] = "src"
-        env.pop("JAX_PLATFORMS", None)
+        env["JAX_PLATFORMS"] = "cpu"  # forced host devices, never a chip
         res = subprocess.run(
             [sys.executable, "-c", SUBPROCESS_PROG],
             capture_output=True,

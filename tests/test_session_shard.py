@@ -352,6 +352,74 @@ class TestRuntimePublicAPI:
 
 
 # ---------------------------------------------------------------------------
+# --check-parity's bar on host snapshots
+# ---------------------------------------------------------------------------
+
+
+def _snapshot(seed: int = 0) -> dict:
+    rng = np.random.default_rng(seed)
+    return {
+        "adapters": {
+            "t0": {"A": rng.normal(size=(2, 8, 4)).astype(np.float32),
+                   "B": rng.normal(size=(2, 4, 8)).astype(np.float32)},
+        },
+        "losses": {"adapt/r0": {"t0": rng.uniform(5, 7, size=(2, 3)).astype(np.float32)}},
+        "tokens": {"serve/base": rng.integers(0, 503, size=(3, 4))},
+        "slots": {"t0": 1},
+    }
+
+
+def _perturb(snap: dict, what: str, eps: float) -> dict:
+    twin = {k: v for k, v in snap.items()}
+    if what == "adapters":
+        twin["adapters"] = {"t0": {**snap["adapters"]["t0"],
+                                   "B": snap["adapters"]["t0"]["B"] + eps}}
+    elif what == "losses":
+        twin["losses"] = {"adapt/r0": {"t0": snap["losses"]["adapt/r0"]["t0"] * (1 + eps)}}
+    elif what == "tokens":
+        toks = snap["tokens"]["serve/base"].copy()
+        toks[1, 2] = (toks[1, 2] + 1) % 503
+        twin["tokens"] = {"serve/base": toks}
+    elif what == "slots":
+        twin["slots"] = {"t0": 2}
+    return twin
+
+
+class TestParityBar:
+    """``run.parity_diffs``: bitwise on a data mesh; on a model axis,
+    tokens and slot tables exact, adapters/losses within rtol 1e-3 / atol
+    1e-5 — a float32 reduction-order difference passes, a fault does not."""
+
+    @pytest.mark.parametrize("n_model,what,eps,expect", [
+        (1, None, 0.0, []),
+        (1, "adapters", 1e-6, ["adapters[t0][B]"]),
+        (2, None, 0.0, []),
+        (2, "adapters", 3e-6, []),
+        (2, "losses", 1e-5, []),
+        (2, "adapters", 1e-2, ["adapters[t0][B]"]),
+        (2, "losses", 1e-2, ["losses[adapt/r0][t0]"]),
+        (2, "tokens", 0.0, ["tokens[serve/base]"]),
+        (2, "slots", 0.0, ["pool slot tables"]),
+    ])
+    def test_bar(self, n_model, what, eps, expect):
+        from types import SimpleNamespace
+
+        from repro.launch import run as RUN
+
+        session = SimpleNamespace(names=["t0"], n_model=n_model)
+        snap = _snapshot()
+        twin = _perturb(snap, what, eps) if what else _snapshot()
+        diffs, measured = RUN.parity_diffs(session, snap, twin)
+        assert diffs == expect
+        assert measured["token_agreement"] == (
+            1.0 - 1 / 12 if what == "tokens" else 1.0
+        )
+        assert measured["adapter_elements_outside_bar"] == (
+            64.0 if expect == ["adapters[t0][B]"] else 0.0
+        )
+
+
+# ---------------------------------------------------------------------------
 # Forced multi-device tier (subprocess; nightly/full)
 # ---------------------------------------------------------------------------
 
