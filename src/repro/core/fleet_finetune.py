@@ -50,6 +50,7 @@ import numpy as np
 
 from repro.core import lm_skiplora as SL
 from repro.core.skip_cache import SkipCache, cache_read, cache_write
+from repro.core.spans import jit_named
 from repro.kernels.skip_lora.ops import (
     skip_lora_grouped_train,
     skip_lora_grouped_train_int8,
@@ -295,14 +296,18 @@ def make_fleet_cached_epoch(
             t, o, per = step(params, t, o, cache_read(cache, idx), row_tenant)
             return (t, o), per
 
-        (stacked, opt_state), losses = jax.lax.scan(
-            body, (stacked, opt_state), idx_mat
-        )
+        # The scope goes round the scan: round the step's own gradient it
+        # would rename the backward kernel's op in the device trace.
+        with jax.named_scope("adapt_step"):
+            (stacked, opt_state), losses = jax.lax.scan(
+                body, (stacked, opt_state), idx_mat
+            )
         return stacked, opt_state, losses
 
     if not jit:
         return epoch
-    return jax.jit(epoch, donate_argnums=(1, 2) if donate else ())
+    return jit_named("fleet_cached_epoch", epoch,
+                     donate_argnums=(1, 2) if donate else ())
 
 
 def make_fleet_eval_loss(
@@ -330,7 +335,7 @@ def make_fleet_eval_loss(
         )
         return per
 
-    return jax.jit(eval_loss) if jit else eval_loss
+    return jit_named("fleet_eval_loss", eval_loss) if jit else eval_loss
 
 
 def make_fleet_cached_epoch_eval(
@@ -374,13 +379,15 @@ def make_fleet_cached_epoch_eval(
             t, o, per = step(params, t, o, cache_read(cache, idx), row_tenant)
             return (t, o), per
 
-        (stacked, opt_state), losses = jax.lax.scan(
-            body, (stacked, opt_state), idx_mat
-        )
+        with jax.named_scope("adapt_step"):
+            (stacked, opt_state), losses = jax.lax.scan(
+                body, (stacked, opt_state), idx_mat
+            )
         post = held_out(stacked) if eval_post else None
         return stacked, opt_state, losses, pre, post
 
-    return jax.jit(epoch, donate_argnums=(1, 2) if donate else ())
+    return jit_named("fleet_cached_epoch_eval", epoch,
+                     donate_argnums=(1, 2) if donate else ())
 
 
 def make_fleet_populate_epoch(
@@ -439,7 +446,8 @@ def make_fleet_populate_epoch(
 
     if not jit:
         return epoch
-    return jax.jit(epoch, donate_argnums=(1, 2, 3) if donate else ())
+    return jit_named("fleet_populate_epoch", epoch,
+                     donate_argnums=(1, 2, 3) if donate else ())
 
 
 def fleet_cached_epoch_via_engine(
@@ -528,11 +536,11 @@ def fleet_finetune(
     )
     engine_step = None
     if engine is not None:
-        engine_step = jax.jit(
-            make_fleet_cached_step_from_vals(
+        engine_step = jit_named(
+            "fleet_cached_step", make_fleet_cached_step_from_vals(
                 cfg, sl, opt, n_tenants,
                 use_kernel=use_kernel, freeze_mask=freeze_mask,
-            )
+            ),
         )
 
     losses, times = [], []

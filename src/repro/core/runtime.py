@@ -76,6 +76,9 @@ from repro.core import lm_skiplora as SL
 from repro.core.adapter_pool import ShardedAdapterPool
 from repro.core.cache_engine import CacheStats, TieredCacheEngine
 from repro.core.control_plane import ControlConfig, ControlPlane
+from repro.core.spans import RECORDER, jit_named, span
+# Compile marks live in ``core.spans``; importable here under their old names.
+from repro.core.spans import TRACE_COUNTS, mark_trace as _mark_trace  # noqa: F401
 from repro.models.config import ModelConfig
 from repro.runtime.sharding import (
     ShardScope,
@@ -113,20 +116,6 @@ Params = Any
 #: never rebuild the jit wrapper itself.
 _FN_CACHE: dict[tuple, Any] = {}
 
-#: Trace-time retrace counter: ``_mark_trace(name)`` runs as a Python side
-#: effect INSIDE a jitted function body, so it fires exactly once per trace
-#: (first call and every shape/static-arg retrace) and never on cache hits.
-#: Tests assert e.g. that serving three distinct temperatures leaves
-#: ``TRACE_COUNTS["decode_scan"]`` unchanged after warmup — the
-#: recompile-per-temperature bug regression bar — without reaching into
-#: jit's private ``_cache_size``.
-TRACE_COUNTS: Counter = Counter()
-
-
-def _mark_trace(name: str) -> None:
-    TRACE_COUNTS[name] += 1
-
-
 def compiled(key: tuple, make: Callable[[], Any]):
     """Fetch-or-build a jitted callable under a hashable key. The single
     compiled-fn cache behind serve, ingest, and adapt — the per-launcher
@@ -158,7 +147,7 @@ def _prefill_fn(cfg, scope=None):
                     params, cfg, tokens, caches, adapters=adapters
                 )
 
-        return jax.jit(f)
+        return jit_named("prefill", f)
 
     return _cached_fn("prefill", cfg, make, (scope,))
 
@@ -172,7 +161,7 @@ def _prefill_grouped_fn(cfg, use_kernel: bool, scope=None):
                     use_kernel=use_kernel,
                 )
 
-        return jax.jit(f)
+        return jit_named("prefill_grouped", f)
 
     return _cached_fn("prefill_grouped", cfg, make, (use_kernel, scope))
 
@@ -182,7 +171,6 @@ def _decode_scan_fn(cfg, use_kernel: bool = True, fuse_skip: bool = False,
     def make():
         def f(params, tok0, pos0, caches, key, adapters, pools, idx,
               max_new, temperature, unroll):
-            _mark_trace("decode_scan")
             with scope_ctx(scope):
                 return decode_scan(
                     params, cfg, tok0, pos0, caches, key,
@@ -197,8 +185,8 @@ def _decode_scan_fn(cfg, use_kernel: bool = True, fuse_skip: bool = False,
         # sampling temperature under live traffic. It is traced now (the
         # greedy/temperature select runs inside ``sample_token``), so every
         # temperature shares one compiled decode.
-        return jax.jit(
-            f,
+        return jit_named(
+            "decode_scan", f,
             static_argnums=(8, 10),
             donate_argnums=(3,),
         )
@@ -214,7 +202,7 @@ def _decode_step_fn(cfg, scope=None):
                     params, cfg, tok, pos, caches, adapters=adapters
                 )
 
-        return jax.jit(f)
+        return jit_named("decode_step", f)
 
     return _cached_fn("decode_step", cfg, make, (scope,))
 
@@ -222,12 +210,12 @@ def _decode_step_fn(cfg, scope=None):
 def _ingest_fn(cfg, use_kernel: bool, scope=None):
     def make():
         def f(params, tokens, pools, idx):
-            with scope_ctx(scope):
+            with scope_ctx(scope), jax.named_scope("ingest_forward"):
                 return ingest_prefill(
                     params, cfg, tokens, pools, idx, use_kernel=use_kernel
                 )
 
-        return jax.jit(f)
+        return jit_named("ingest", f)
 
     return _cached_fn("ingest", cfg, make, (use_kernel, scope))
 
@@ -632,6 +620,9 @@ class SessionRuntime:
         self._kv_pools: dict[int, Any] = {}
         self._prefix_indexes: dict[int, Any] = {}
         self.counters = Counter()
+        #: The process's span ring (``core.spans``): the scheduler's ticks,
+        #: ingest and adapt record their host spans there.
+        self.spans = RECORDER
 
     # -- shard arithmetic ----------------------------------------------------
 
@@ -893,6 +884,10 @@ class SessionRuntime:
         last-position logits under the tenant's current adapters (zero slot
         until the first ``adapt`` write-back) — ingestion doubles as
         serving. Returns (B, 1, V) logits."""
+        with span("ingest", rows=int(tokens.shape[0])):
+            return self._ingest(tenant, tokens, labels)
+
+    def _ingest(self, tenant, tokens: jax.Array, labels: jax.Array) -> jax.Array:
         # Validate BEFORE registering: a rejected batch must not leak a
         # cache partition or leave a zombie tenant that poisons adapt().
         st = self._tenants.get(tenant)
@@ -908,14 +903,17 @@ class SessionRuntime:
         if st is None:
             st = self._add_tenant(tenant)
         s = self._shard_of_partition(st.partition)
-        logits, acts, y_base = self._populate(s, tenant, tokens)
-        values = SL._encode_acts(acts, None, self.sl)
-        values["y_base"] = y_base
-        values["labels"] = labels
-        ids = self._local_ids(
-            st.partition, np.arange(st.n_ingested, st.n_ingested + b)
-        )
-        self.engines[s].write(ids, values)
+        with span("ingest.forward"):
+            logits, acts, y_base = self._populate(s, tenant, tokens)
+        with span("ingest.encode"):
+            values = SL._encode_acts(acts, None, self.sl)
+            values["y_base"] = y_base
+            values["labels"] = labels
+            ids = self._local_ids(
+                st.partition, np.arange(st.n_ingested, st.n_ingested + b)
+            )
+        with span("ingest.write"):
+            self.engines[s].write(ids, values)
         self._export[s] = None  # new rows: invalidate adapt's exported view
         st.n_ingested += b
         self.counters["ingest/rows"] += b
@@ -970,6 +968,10 @@ class SessionRuntime:
         Returns {"losses": {tenant: (epochs, steps) np.ndarray}, "groups":
         [group tenant lists], "path": "scan" | "stream"}.
         """
+        with span("adapt", epochs=epochs), span("adapt.prepare") as prep:
+            return self._adapt(tenants, epochs, batch_per_tenant, key, prep)
+
+    def _adapt(self, tenants, epochs, batch_per_tenant, key, prep) -> dict:
         order = [t for t in self._tenants] if tenants is None else list(tenants)
         if not order:
             raise ValueError("no tenants to adapt")
@@ -1001,19 +1003,22 @@ class SessionRuntime:
             ).append(t)
 
         pending = []
-        for (step0, epoch0, spt, shard), group in groups.items():
+        for i, ((step0, epoch0, spt, shard), group) in enumerate(groups.items()):
+            if i:
+                prep = span("adapt.prepare", group=i).open()
             ls_epochs, path = self._adapt_group(
                 group, spt, shard, epochs=epochs, epoch0=epoch0, step0=step0,
-                batch_per_tenant=batch_per_tenant,
+                batch_per_tenant=batch_per_tenant, prep=prep,
             )
             pending.append((group, ls_epochs, path))
         losses: dict[Any, np.ndarray] = {}
         paths = set()
-        for group, ls_epochs, path in pending:  # sync AFTER all dispatches
-            ls = np.stack([np.asarray(l) for l in ls_epochs])
-            paths.add(path)
-            for g, t in enumerate(group):
-                losses[t] = ls[:, :, g]
+        with span("adapt.sync"):
+            for group, ls_epochs, path in pending:  # sync AFTER all dispatches
+                ls = np.stack([np.asarray(l) for l in ls_epochs])
+                paths.add(path)
+                for g, t in enumerate(group):
+                    losses[t] = ls[:, :, g]
         self.counters["adapt/epochs"] += epochs * len(groups)
         return {
             "losses": losses,
@@ -1022,12 +1027,14 @@ class SessionRuntime:
         }
 
     def _adapt_group(
-        self, group, spt, shard, *, epochs, epoch0, step0, batch_per_tenant
+        self, group, spt, shard, *, epochs, epoch0, step0, batch_per_tenant,
+        prep,
     ) -> tuple[list, str]:
         """Dispatch one same-(trajectory, shard) group's cached epochs on
         its shard. Returns the per-epoch (steps, N) loss arrays *without*
         host synchronisation — the caller converts after every group is in
-        flight."""
+        flight. ``prep`` (the open ``adapt.prepare`` span) closes as the
+        first epoch launches."""
         n = len(group)
         device = self._shard_device[shard]
         engine = self.engines[shard]
@@ -1084,10 +1091,12 @@ class SessionRuntime:
         else:
             step_fn = compiled(
                 ("fleet_cached_step", *fn_key),
-                lambda: jax.jit(FF.make_fleet_cached_step_from_vals(
-                    self.cfg, self.sl, self.optimizer, n,
-                    use_kernel=self.use_kernel,
-                )),
+                lambda: jit_named(
+                    "fleet_cached_step", FF.make_fleet_cached_step_from_vals(
+                        self.cfg, self.sl, self.optimizer, n,
+                        use_kernel=self.use_kernel,
+                    ),
+                ),
             )
             if do_eval:
                 ev_fn = compiled(
@@ -1121,41 +1130,46 @@ class SessionRuntime:
                 holdout_every=holdout,
             )
             steps_per_epoch = idx_mat.shape[0]
-            want_pre = do_eval and resident and e == 0
-            want_post = do_eval and resident and e == epochs - 1
-            if want_pre or want_post:
-                # Shadow eval folded into the SAME fused dispatch as the
-                # training scan (one jit per (pre, post) flag pair).
-                eval_epoch_fn = compiled(
-                    ("fleet_cached_epoch_eval", *fn_key, want_pre, want_post),
-                    lambda: FF.make_fleet_cached_epoch_eval(
-                        self.cfg, self.sl, self.optimizer, n,
-                        use_kernel=self.use_kernel,
-                        eval_pre=want_pre, eval_post=want_post, donate=False,
-                    ),
-                )
-                with scope_ctx(scope):
-                    stacked, opt_state, ls, pre, post = eval_epoch_fn(
-                        self._shard_params[shard], stacked, opt_state, cache,
-                        jnp.asarray(idx_mat), row_tenant,
-                        eval_idx, eval_row_tenant,
+            prep.close()
+            with span("adapt.launch", epoch=e):
+                want_pre = do_eval and resident and e == 0
+                want_post = do_eval and resident and e == epochs - 1
+                if want_pre or want_post:
+                    # Shadow eval folded into the SAME fused dispatch as the
+                    # training scan (one jit per (pre, post) flag pair).
+                    eval_epoch_fn = compiled(
+                        ("fleet_cached_epoch_eval", *fn_key, want_pre,
+                         want_post),
+                        lambda: FF.make_fleet_cached_epoch_eval(
+                            self.cfg, self.sl, self.optimizer, n,
+                            use_kernel=self.use_kernel, eval_pre=want_pre,
+                            eval_post=want_post, donate=False,
+                        ),
                     )
-                if want_pre:
-                    pre_loss = pre
-                if want_post:
-                    post_loss = post
-            elif resident:
-                with scope_ctx(scope):
-                    stacked, opt_state, ls = epoch_fn(
-                        self._shard_params[shard], stacked, opt_state, cache,
-                        jnp.asarray(idx_mat), row_tenant,
-                    )
-            else:
-                with scope_ctx(scope):
-                    stacked, opt_state, ls = FF.fleet_cached_epoch_via_engine(
-                        step_fn, self._shard_params[shard], stacked, opt_state,
-                        engine, idx_mat, row_tenant,
-                    )
+                    with scope_ctx(scope):
+                        stacked, opt_state, ls, pre, post = eval_epoch_fn(
+                            self._shard_params[shard], stacked, opt_state,
+                            cache, jnp.asarray(idx_mat), row_tenant,
+                            eval_idx, eval_row_tenant,
+                        )
+                    if want_pre:
+                        pre_loss = pre
+                    if want_post:
+                        post_loss = post
+                elif resident:
+                    with scope_ctx(scope):
+                        stacked, opt_state, ls = epoch_fn(
+                            self._shard_params[shard], stacked, opt_state,
+                            cache, jnp.asarray(idx_mat), row_tenant,
+                        )
+                else:
+                    with scope_ctx(scope):
+                        stacked, opt_state, ls = (
+                            FF.fleet_cached_epoch_via_engine(
+                                step_fn, self._shard_params[shard], stacked,
+                                opt_state, engine, idx_mat, row_tenant,
+                            )
+                        )
             all_losses.append(ls)
 
         if do_eval and not resident:
@@ -1169,19 +1183,28 @@ class SessionRuntime:
         # device and serialise the per-shard groups we just overlapped.
         step_after = step0 + steps_per_epoch * epochs
 
-        if self.control is None:
-            for g, (t, st) in enumerate(zip(group, states)):
-                st.adapters = jax.tree.map(lambda x: x[g], stacked)
-                st.opt_mu = _maybe_slice(opt_state.mu, g)
-                st.opt_nu = _maybe_slice(opt_state.nu, g)
-                st.step = step_after
-                st.epochs_done = epoch0 + epochs
-            self.pool.register_many(group, stacked)
-            for t in group:
-                self.pool.pin(t)  # in-flight session state: never LRU-evicted
-            return all_losses, "scan" if resident else "stream"
+        with span("adapt.writeback"):
+            if self.control is None:
+                for g, (t, st) in enumerate(zip(group, states)):
+                    st.adapters = jax.tree.map(lambda x: x[g], stacked)
+                    st.opt_mu = _maybe_slice(opt_state.mu, g)
+                    st.opt_nu = _maybe_slice(opt_state.nu, g)
+                    st.step = step_after
+                    st.epochs_done = epoch0 + epochs
+                self.pool.register_many(group, stacked)
+                for t in group:
+                    self.pool.pin(t)  # in-flight session state: never evicted
+            else:
+                self._gated_writeback(
+                    group, states, stacked, opt_state, pre_loss, post_loss,
+                    step_after, epoch0 + epochs,
+                )
+        return all_losses, "scan" if resident else "stream"
 
-        # -- gated write-back (control plane on) -----------------------------
+    def _gated_writeback(self, group, states, stacked, opt_state, pre_loss,
+                         post_loss, step_after, epochs_done) -> None:
+        """Write-back with the control plane on: each tenant's eval losses
+        decide whether its trained adapters replace the served ones."""
         # The gate needs the eval losses on host NOW, which synchronises this
         # group before the next one dispatches — the (documented, opt-in)
         # price of deciding a write-back on its measured outcome.
@@ -1211,7 +1234,7 @@ class SessionRuntime:
             st.opt_mu = _maybe_slice(opt_state.mu, g)
             st.opt_nu = _maybe_slice(opt_state.nu, g)
             st.step = step_after
-            st.epochs_done = epoch0 + epochs
+            st.epochs_done = epochs_done
         self.pool.register_many(
             group, stacked, gate=decisions.__getitem__, meta=meta,
         )
@@ -1234,7 +1257,6 @@ class SessionRuntime:
             self.control.record_rollback(t, auto=True)
             self.counters["control/rollbacks"] += 1
             self.counters["control/auto_rollbacks"] += 1
-        return all_losses, "scan" if resident else "stream"
 
     # -- control plane -------------------------------------------------------
 

@@ -65,6 +65,7 @@ import numpy as np
 
 from repro.core import batch_plan
 from repro.core import runtime as RT
+from repro.core.spans import jit_named, span
 from repro.models.blocks import ATTN_KINDS
 from repro.models.lm import (
     decode_step,
@@ -94,6 +95,7 @@ class Request:
     temperature: float = 0.0
     submitted_at: float = 0.0
     started_at: Optional[float] = None
+    first_token_at: Optional[float] = None   # host holds the first token
     finished_at: Optional[float] = None
     tokens: list = dataclasses.field(default_factory=list)
     done: bool = False
@@ -160,9 +162,10 @@ def _chunk_scan(params, cfg, use_kernel, fuse, chunk, pools, idx, caches, tok,
         npos = jnp.where(active, jnp.minimum(npos, max_seq - 1), pos)
         return (ntok, npos, caches, key), ntok[:, 0]
 
-    (tok, pos, caches, key), toks = jax.lax.scan(
-        body, (tok, pos, caches, key), None, length=chunk
-    )
+    with jax.named_scope("decode"):
+        (tok, pos, caches, key), toks = jax.lax.scan(
+            body, (tok, pos, caches, key), None, length=chunk
+        )
     return caches, tok, pos, toks
 
 
@@ -170,14 +173,13 @@ def _sched_step_fn(cfg, use_kernel: bool, chunk: int, max_seq: int,
                    fuse: bool = False, scope=None):
     def make():
         def f(params, pools, idx, caches, tok, pos, active, temps, key):
-            RT._mark_trace("sched_step")
             with scope_ctx(scope):
                 return _chunk_scan(
                     params, cfg, use_kernel, fuse, chunk, pools, idx, caches,
                     tok, pos, active, temps, key, max_seq,
                 )
 
-        return jax.jit(f, donate_argnums=(3,))
+        return jit_named("sched_step", f, donate_argnums=(3,))
 
     return RT.compiled(
         ("sched_step", cfg, use_kernel, chunk, max_seq, fuse, scope), make
@@ -189,13 +191,13 @@ def _sched_admit_fn(cfg, use_kernel: bool, chunk: int, max_seq: int,
     def make():
         def f(params, pools, idx, new_tokens, new_lens, new_idx, new_rows,
               caches, tok, pos, active, temps, key):
-            RT._mark_trace("sched_admit")
             with scope_ctx(scope):
                 akey, key = jax.random.split(key)
-                logits, new_caches = sched_prefill(
-                    params, cfg, new_tokens, new_lens, pools, new_idx,
-                    use_kernel=use_kernel,
-                )
+                with jax.named_scope("admit_prefill"):
+                    logits, new_caches = sched_prefill(
+                        params, cfg, new_tokens, new_lens, pools, new_idx,
+                        use_kernel=use_kernel,
+                    )
                 b = tok.shape[0]
                 row_t = jnp.take(temps, jnp.clip(new_rows, 0, b - 1))
                 tok0, _ = sample_token(logits, akey, row_t)
@@ -215,7 +217,7 @@ def _sched_admit_fn(cfg, use_kernel: bool, chunk: int, max_seq: int,
                 )
                 return caches, tok, pos, toks, tok0
 
-        return jax.jit(f, donate_argnums=(7,))
+        return jit_named("sched_admit", f, donate_argnums=(7,))
 
     return RT.compiled(
         ("sched_admit", cfg, use_kernel, chunk, max_seq, bucket, prompt, fuse,
@@ -237,13 +239,14 @@ def _sched_admit_pipe_fn(cfg, use_kernel: bool, chunk: int, max_seq: int,
     def make():
         def f(params, stage_blocks, valid, pools, idx, new_tokens, new_lens,
               new_idx, new_rows, caches, tok, pos, active, temps, key):
-            RT._mark_trace("sched_admit_pipe")
             with scope_ctx(scope):
                 akey, key = jax.random.split(key)
-                logits, new_caches = pipeline_sched_prefill(
-                    params, cfg, stage_blocks, valid, new_tokens, new_lens,
-                    pools, new_idx, mesh=scope.mesh, n_micro=n_micro,
-                )
+                with jax.named_scope("admit_prefill"):
+                    logits, new_caches = pipeline_sched_prefill(
+                        params, cfg, stage_blocks, valid, new_tokens,
+                        new_lens, pools, new_idx, mesh=scope.mesh,
+                        n_micro=n_micro,
+                    )
                 b = tok.shape[0]
                 row_t = jnp.take(temps, jnp.clip(new_rows, 0, b - 1))
                 tok0, _ = sample_token(logits, akey, row_t)
@@ -263,7 +266,7 @@ def _sched_admit_pipe_fn(cfg, use_kernel: bool, chunk: int, max_seq: int,
                 )
                 return caches, tok, pos, toks, tok0
 
-        return jax.jit(f, donate_argnums=(9,))
+        return jit_named("sched_admit_pipe", f, donate_argnums=(9,))
 
     return RT.compiled(
         ("sched_admit_pipe", cfg, use_kernel, chunk, max_seq, bucket, prompt,
@@ -289,7 +292,6 @@ def _sched_admit_reuse_fn(cfg, use_kernel: bool, chunk: int, max_seq: int,
         def f(params, pools, idx, pool_data, tables, tail_tokens, tail_lens,
               prefix_lens, new_idx, new_rows, caches, tok, pos, active,
               temps, key):
-            RT._mark_trace("sched_admit_reuse")
             with scope_ctx(scope):
                 from repro.core.kv_pool import gather_blocks
                 from repro.models.lm import init_serve_caches
@@ -306,10 +308,11 @@ def _sched_admit_reuse_fn(cfg, use_kernel: bool, chunk: int, max_seq: int,
                     ),
                     adm, prefix,
                 )
-                logits, new_caches = sched_prefill_reuse(
-                    params, cfg, tail_tokens, tail_lens, prefix_lens, adm,
-                    pools, new_idx, use_kernel=use_kernel,
-                )
+                with jax.named_scope("admit_prefill"):
+                    logits, new_caches = sched_prefill_reuse(
+                        params, cfg, tail_tokens, tail_lens, prefix_lens, adm,
+                        pools, new_idx, use_kernel=use_kernel,
+                    )
                 b = tok.shape[0]
                 row_t = jnp.take(temps, jnp.clip(new_rows, 0, b - 1))
                 tok0, _ = sample_token(logits, akey, row_t)
@@ -329,7 +332,7 @@ def _sched_admit_reuse_fn(cfg, use_kernel: bool, chunk: int, max_seq: int,
                 )
                 return caches, tok, pos, toks, tok0
 
-        return jax.jit(f, donate_argnums=(10,))
+        return jit_named("sched_admit_reuse", f, donate_argnums=(10,))
 
     return RT.compiled(
         ("sched_admit_reuse", cfg, use_kernel, chunk, max_seq, bucket, prompt,
@@ -576,11 +579,20 @@ class RequestScheduler:
     def step(self) -> int:
         """One scheduler tick: admit + dispatch on every shard with work,
         harvest the produced tokens, then run queued ingest work. Returns
-        the number of dispatches issued."""
-        plans = self._plan()
+        the number of dispatches issued. The tick is the ``sched.step``
+        span (``rids``: the requests its first wave decodes), with
+        ``sched.plan``, ``sched.launch``, ``sched.wait``, ``sched.harvest``
+        and ``sched.ingest`` inside."""
+        with span("sched.step") as tick:
+            return self._step(tick)
+
+    def _step(self, tick) -> int:
+        with span("sched.plan"):
+            plans = self._plan()
         issued = []
         for shard, admits in plans:
             issued.append(self._dispatch(shard, admits))
+        tick.set(rids=self._live_rids())
         done0 = self.counters["completed"]
         for shard, admits, out in issued:    # async dispatch, sync here
             self._harvest(shard, admits, out)
@@ -594,10 +606,9 @@ class RequestScheduler:
         # other live rows simply advance an extra chunk — per-row decode
         # is asynchronous by construction, so that is just an extra tick).
         if self._pending and self.counters["completed"] > done0:
-            extra = [
-                self._dispatch(shard, admits)
-                for shard, admits in self._plan() if admits
-            ]
+            with span("sched.plan"):
+                waves = [(s, a) for s, a in self._plan() if a]
+            extra = [self._dispatch(shard, admits) for shard, admits in waves]
             for shard, admits, out in extra:
                 self._harvest(shard, admits, out)
             if extra:
@@ -672,6 +683,12 @@ class RequestScheduler:
         return pool, self.rt.prefix_index(shard)
 
     def _dispatch(self, shard: int, admits: list[Request]):
+        """Launch one shard's dispatch: the ``sched.launch`` span (``shard``,
+        ``kind``, ``rids``: every request the dispatch decodes)."""
+        with span("sched.launch", shard=shard) as launch:
+            return self._launch(launch, shard, admits)
+
+    def _launch(self, launch, shard: int, admits: list[Request]):
         lb = self._batch(shard)
         matches = None
         pool = pidx = None
@@ -701,6 +718,11 @@ class RequestScheduler:
             lb.temps[row] = req.temperature
             self._in_flight[req.tenant] += 1
             req.started_at = now
+        launch.set(
+            kind=("step" if not admits else "admit_reuse" if matches is not None
+                  else "admit_pipe" if self.pipeline else "admit"),
+            rids=self._live_rids(shard),
+        )
         lb.idx_version = None            # occupancy changed: re-resolve slots
         self._refresh_idx(shard, lb)
         params = self.rt._shard_params[shard]
@@ -898,19 +920,29 @@ class RequestScheduler:
     def _harvest(self, shard: int, admitted, out) -> None:
         lb = self._batch(shard)
         toks, tok0 = out
-        toks = np.asarray(toks)                      # (chunk, B) sync point
-        if tok0 is not None:
-            tok0 = np.asarray(tok0)                  # (bucket, 1)
-            for j, (req, row) in enumerate(admitted):
-                req.tokens.append(int(tok0[j, 0]))
-        for row, req in enumerate(lb.rows):
-            if req is None or not lb.active[row]:
-                continue
-            need = req.max_new - len(req.tokens)
-            req.tokens.extend(int(t) for t in toks[: max(need, 0), row])
-            if len(req.tokens) >= req.max_new:
-                self._finish(lb, row, req)
-        self.counters["steps"] += self.chunk
+        with span("sched.wait", shard=shard):
+            toks = np.asarray(toks)                  # (chunk, B) sync point
+            if tok0 is not None:
+                tok0 = np.asarray(tok0)              # (bucket, 1)
+        with span("sched.harvest", shard=shard):
+            if tok0 is not None:
+                now = time.perf_counter()
+                for j, (req, row) in enumerate(admitted):
+                    req.tokens.append(int(tok0[j, 0]))
+                    req.first_token_at = now
+            for row, req in enumerate(lb.rows):
+                if req is None or not lb.active[row]:
+                    continue
+                need = req.max_new - len(req.tokens)
+                req.tokens.extend(int(t) for t in toks[: max(need, 0), row])
+                if len(req.tokens) >= req.max_new:
+                    self._finish(lb, row, req)
+
+    def _live_rids(self, shard: Optional[int] = None) -> tuple:
+        """Ids of the requests in the live batches (of one shard)."""
+        batches = (self._batches.values() if shard is None
+                   else [self._batches[shard]])
+        return tuple(r.rid for lb in batches for r in lb.rows if r is not None)
 
     def _finish(self, lb: _LiveBatch, row: int, req: Request) -> None:
         req.done = True
@@ -926,8 +958,10 @@ class RequestScheduler:
         self.counters["tokens"] += req.max_new
 
     def _run_ingest(self) -> None:
-        while self._ingest_queue:
-            req = self._ingest_queue.popleft()
-            req.logits = self.rt.ingest(req.tenant, req.tokens, req.labels)
-            req.done = True
-            self.counters["ingested"] += 1
+        if not self._ingest_queue:
+            return
+        with span("sched.ingest", requests=len(self._ingest_queue)):
+            while self._ingest_queue:
+                req = self._ingest_queue.popleft()
+                req.logits = self.rt.ingest(req.tenant, req.tokens, req.labels)
+                req.done = True
