@@ -146,7 +146,11 @@ def _chunk_scan(params, cfg, use_kernel, fuse, chunk, pools, idx, caches, tok,
     received every carried token, so emitting the new one means each chunk
     hands back exactly the tokens the host has not seen. ``fuse`` inlines
     each step's skip term as dense math (no grouped kernel dispatch inside
-    the scan body) — temp-0 tokens are identical either way (tested)."""
+    the scan body) — temp-0 tokens are identical either way (tested).
+
+    The caches ride in the carry of this scan and of the layer scan inside
+    each step (``blocks.stack_forward``), so a step writes one token per
+    row per attention layer in place and copies no cache."""
 
     def body(carry, _):
         tok, pos, caches, key = carry

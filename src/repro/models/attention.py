@@ -11,7 +11,8 @@ Covers every attention variant among the assigned architectures:
 Three entry points share one core:
   - ``attn_train``: full-sequence causal attention (training / scoring).
   - ``attn_prefill``: same, but also returns the populated KV cache.
-  - ``attn_decode``: single-token step against a pre-allocated ring cache.
+  - ``attn_decode``: single-token step against a pre-allocated cache, one
+    layer's or the decode layer scan's stacked carry.
 """
 
 from __future__ import annotations
@@ -108,7 +109,7 @@ def _sdpa(
 
 def causal_mask(sq: int, sk: int, q_offset, window: int) -> jax.Array:
     """(1, sq, sk) mask: key t attends iff t <= q_pos and q_pos - t < window."""
-    q_pos = jnp.arange(sq) + q_offset  # may be traced (decode)
+    q_pos = jnp.arange(sq) + q_offset
     k_pos = jnp.arange(sk)
     m = k_pos[None, :] <= q_pos[:, None]
     if window > 0:
@@ -224,45 +225,64 @@ def attn_prefill_ext(
     return y, {"k": ck, "v": cv}
 
 
+def _write_token(cache: jax.Array, t: jax.Array, pos, layer) -> jax.Array:
+    """Write one token per row, ``t`` (b, 1, nk, hd), at ``pos`` of one
+    layer's (b, S, nk, hd) cache, or at ``(layer, row, pos)`` of the
+    stacked (L, b, S, nk, hd) cache when ``layer`` is given."""
+    t = t.astype(cache.dtype)
+    if getattr(pos, "ndim", 0) > 0:
+        rows = jnp.arange(t.shape[0])
+        at = (rows, pos) if layer is None else (layer, rows, pos)
+        return cache.at[at].set(t[:, 0])
+    if layer is None:
+        return jax.lax.dynamic_update_slice(cache, t, (0, pos, 0, 0))
+    return jax.lax.dynamic_update_slice(cache, t[None], (layer, 0, pos, 0, 0))
+
+
 def attn_decode(
     params: Params,
     x: jax.Array,                 # (b, 1, d)
     pos: jax.Array,               # scalar int32 OR (b,) int32 per-row index
     spec: AttnSpec,
     cache: dict[str, jax.Array],
+    layer: Optional[jax.Array] = None,
 ) -> tuple[jax.Array, dict[str, jax.Array]]:
-    """One decode step: write K/V at ``pos``, attend over cache[0:pos+1].
+    """One decode step: write K/V at ``pos``, then attend over the cache
+    with keys at positions <= ``pos`` (and inside the window) unmasked.
 
     ``pos`` is a scalar on the classic whole-batch path (every row at the
-    same sequence position; this branch is kept byte-identical so fused
-    ``decode_scan`` traces are unchanged). A (b,) vector selects the
-    continuous-batching path: each row writes its K/V at its own position
-    and masks keys per row — what the request scheduler needs once rows
-    admitted at different times share one live batch."""
+    same sequence position, written by ``dynamic_update_slice``). A (b,)
+    vector selects the continuous-batching path: each row scatters its
+    K/V at its own position and masks keys per row — what the request
+    scheduler needs once rows admitted at different times share one live
+    batch.
+
+    ``cache`` holds one layer's (b, S, nk, hd) K/V, or, with a ``layer``
+    index, the whole stacked (L, b, S, nk, hd) K/V that the decode layer
+    scan carries: the token is written in place at ``(layer, row, pos)``
+    and the layer is read straight from the carry, so a step moves one
+    token per row instead of copying the layer out and back. Returns
+    ``(y, cache)`` with the cache in the form it was given."""
     b = x.shape[0]
-    if getattr(pos, "ndim", 0) > 0:
-        positions = pos[:, None].astype(jnp.int32)               # (b, 1)
-        q, k, v = _qkv(params, x, positions, spec)
-        rows = jnp.arange(b)
-        ck = cache["k"].at[rows, pos].set(k[:, 0].astype(cache["k"].dtype))
-        cv = cache["v"].at[rows, pos].set(v[:, 0].astype(cache["v"].dtype))
-        sk = ck.shape[1]
-        k_pos = jnp.arange(sk)
-        mask = k_pos[None, None, :] <= pos[:, None, None]        # (b, 1, sk)
-        if spec.window > 0:
-            mask &= k_pos[None, None, :] > (pos[:, None, None] - spec.window)
-        out = _sdpa(q, ck.astype(x.dtype), cv.astype(x.dtype), mask, spec)
-        y = jnp.einsum("bsnh,nhd->bsd", out, params["wo"].astype(x.dtype))
-        return y, {"k": ck, "v": cv}
-    positions = jnp.broadcast_to(pos[None, None], (b, 1)).astype(jnp.int32)
+    positions = jnp.broadcast_to(
+        jnp.reshape(pos, (-1, 1)), (b, 1)).astype(jnp.int32)     # (b, 1)
     q, k, v = _qkv(params, x, positions, spec)
-    ck = jax.lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype), (0, pos, 0, 0))
-    cv = jax.lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype), (0, pos, 0, 0))
-    sk = ck.shape[1]
-    mask = causal_mask(1, sk, pos, spec.window)
+    new_cache = {n: _write_token(cache[n], t, pos, layer)
+                 for n, t in (("k", k), ("v", v))}
+    if layer is None:
+        ck, cv = new_cache["k"], new_cache["v"]
+    else:
+        new_cache = {n: constrain(c, None, "batch", "seq", "heads", None)
+                     for n, c in new_cache.items()}
+        ck, cv = (jax.lax.dynamic_index_in_dim(new_cache[n], layer, 0, False)
+                  for n in ("k", "v"))
+    k_pos = jnp.arange(ck.shape[1])
+    mask = k_pos[None, None, :] <= positions[:, :, None]         # (b, 1, sk)
+    if spec.window > 0:
+        mask &= k_pos[None, None, :] > (positions[:, :, None] - spec.window)
     out = _sdpa(q, ck.astype(x.dtype), cv.astype(x.dtype), mask, spec)
     y = jnp.einsum("bsnh,nhd->bsd", out, params["wo"].astype(x.dtype))
-    return y, {"k": ck, "v": cv}
+    return y, new_cache
 
 
 def attn_decode_paged(

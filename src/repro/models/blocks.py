@@ -121,8 +121,12 @@ def block_forward(
     mode: str,                     # "train" | "prefill" | "decode"
     cache: Optional[Params] = None,
     pos: Optional[jax.Array] = None,
+    layer: Optional[jax.Array] = None,
 ) -> tuple[jax.Array, Optional[Params], jax.Array]:
-    """Apply one block. Returns (h_out, new_cache, moe_aux_loss)."""
+    """Apply one block. Returns (h_out, new_cache, moe_aux_loss).
+
+    ``layer`` (decode, attention kinds) marks ``cache`` as the stacked
+    (L, ...) K/V of the block's period position; see ``A.attn_decode``."""
     aux = jnp.zeros((), jnp.float32)
     x = _norm(cfg, params["norm1"], h)
     new_cache = None
@@ -142,7 +146,9 @@ def block_forward(
             else:
                 y, new_cache = A.attn_prefill(params["attn"], x, spec, cache)
         else:
-            y, new_cache = A.attn_decode(params["attn"], x, pos, spec, cache)
+            y, new_cache = A.attn_decode(
+                params["attn"], x, pos, spec, cache, layer
+            )
     elif kind == "mamba":
         if mode == "train":
             y, _ = S.mamba_seq(params["mamba"], x, cfg, None)
@@ -253,14 +259,26 @@ def stack_forward(
     caches       : updated caches (prefill/decode) or None
     acts         : per-layer block inputs (n_layers, B, S, D) if collect_acts
     aux          : summed MoE aux loss
+
+    ``caches`` has the ``init_stack_caches`` layout. Prefill scans each
+    period position's stacked caches as ``xs`` and returns them as ``ys``.
+    Decode does so only for recurrent states (rewritten whole each step,
+    and small); an attention position's stacked (n_periods, B, S, nk, hd)
+    K/V rides in the scan's carry instead, and each layer writes its one
+    new token in place at ``(layer, row, pos)`` and reads its slice from
+    the carry, so no step copies a layer or the whole cache. Remainder
+    layers keep their own per-layer caches.
     """
     period = cfg.period
     skip0 = jnp.zeros_like(h)
     aux0 = jnp.zeros((), jnp.float32)
+    carried = [mode == "decode" and caches is not None and kind in ATTN_KINDS
+               for kind in cfg.pattern]
 
     def period_body(carry, xs):
-        hh, skip, aux = carry
-        p_params, p_caches, p_adapters = xs
+        hh, skip, aux, kv = carry
+        p_params, p_caches, p_adapters, layer = xs
+        kv = list(kv)
         new_caches = []
         acts = []
         for i, kind in enumerate(cfg.pattern):
@@ -274,34 +292,43 @@ def stack_forward(
                 hh,
                 cfg,
                 mode=mode,
-                cache=None if p_caches is None else p_caches[i],
+                cache=kv[i] if carried[i] else p_caches[i],
                 pos=pos,
+                layer=layer if carried[i] else None,
             )
             hh = constrain(hh, "batch", "seq", None)
+            if carried[i]:
+                kv[i], c_new = c_new, None
             new_caches.append(c_new)
             aux = aux + a
         ys = (
             new_caches if mode != "train" else None,
             jnp.stack(acts, axis=0) if collect_acts else None,
         )
-        return (hh, skip, aux), ys
+        return (hh, skip, aux, kv), ys
 
+    periods = [None] * period if caches is None else caches["periods"]
+    kv0 = [c if k else None for c, k in zip(periods, carried)]
     xs = (
         stack["periods"],
-        None if caches is None else caches["periods"],
+        [None if k else c for c, k in zip(periods, carried)],
         None if adapters is None else adapters["periods"],
+        jnp.arange(cfg.n_periods) if any(carried) else None,
     )
     body = period_body
     if mode == "train":
         # Rematerialise each period in the backward pass: the scan otherwise
         # saves every block's internals (incl. attention probs) per period.
         body = jax.checkpoint(period_body)
-    (h, skip, aux), (period_caches, period_acts) = jax.lax.scan(
+    (h, skip, aux, kv), (period_caches, period_acts) = jax.lax.scan(
         body,
-        (h, skip0, aux0),
+        (h, skip0, aux0, kv0),
         xs,
         unroll=cfg.n_periods if _SCAN_UNROLL.get() else 1,
     )
+    if any(carried):
+        period_caches = [c if k else pc
+                         for c, pc, k in zip(kv, period_caches, carried)]
 
     # Remainder layers (unrolled).
     rem_caches = []

@@ -13,9 +13,11 @@ this file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -213,8 +215,6 @@ def _program(name: str, sharding):
 
 @pytest.mark.parametrize("name", sorted(PROGRAM_KERNELS))
 def test_program_keeps_kernel_op_names(name, one_chip, mosaic):
-    import re
-
     text = _program(name, one_chip).compile().as_text()
     assert re.search(rf"^HloModule jit_{name},", text, re.M)
     kernels = {
@@ -224,3 +224,80 @@ def test_program_keeps_kernel_op_names(name, one_chip, mosaic):
             text, re.M)
     }
     assert kernels == PROGRAM_KERNELS[name]
+
+
+# -- a decode step writes one token into the carried KV cache ----------------
+#
+# The decode layer scan carries each attention position's stacked K/V and
+# writes the step's token into it in place. Inside the decode loops (the
+# chunk scan and the layer scan within it) no op may copy, slice out or
+# write back the stacked cache or one layer of it; the one cache-shaped op
+# left is the in-place write whose update is one token per row.
+
+_INSTR = re.compile(
+    r"^\s*(ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\]\S* ([\w\-]+)\(([^)]*)\)")
+_MOVES = {"copy", "copy-done", "dynamic-slice", "dynamic-update-slice",
+          "scatter", "fusion"}
+_UPDATE = {"scatter": 2, "dynamic-update-slice": 1}
+
+
+def _decode_loop_cache_moves(text, cache_shapes, token_elems):
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            cur = comps.setdefault(head[1], [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    shape, root = {}, {}
+    for name, lines in comps.items():
+        for line in lines:
+            m = _INSTR.match(line)
+            if m:
+                shape[m[2]] = tuple(int(d) for d in m[3].split(",") if d)
+                if m[1]:
+                    root[name] = m
+
+    def token_write(m):
+        i = _UPDATE.get(m[4])
+        if i is None:
+            return False
+        upd = re.findall(r"%([\w.\-]+)", m[5])[i]
+        return int(np.prod(shape[upd])) <= token_elems
+
+    todo = re.findall(r"while\([^\n]*?body=%([\w.\-]+)[^\n]*?"
+                      r"op_name=\"jit\(\w+\)/decode/while", text)
+    assert todo, "no decode loop found"
+    seen, moves = set(), []
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            todo += re.findall(r"(?:body|to_apply)=%([\w.\-]+)", line)
+            m = _INSTR.match(line)
+            if not m or m[4] not in _MOVES or shape[m[2]] not in cache_shapes:
+                continue
+            if m[4] == "fusion":
+                called = re.search(r"calls=%([\w.\-]+)", line)[1]
+                if token_write(root[called]):
+                    continue
+            elif token_write(m):
+                continue
+            moves.append(line.strip()[:160])
+    return moves
+
+
+@pytest.mark.parametrize("name", ["sched_step", "sched_admit"])
+def test_decode_loop_moves_no_whole_cache(name, one_chip, mosaic):
+    from repro.configs import get_config
+
+    cfg = get_config("musicgen-medium")
+    nk, hd = cfg.n_kv_heads, cfg.resolved_head_dim
+    layer = (4, 128, nk, hd)                 # _program's rows and max_seq
+    text = _program(name, one_chip).compile().as_text()
+    moves = _decode_loop_cache_moves(text, {(2,) + layer, layer}, 4 * nk * hd)
+    assert moves == []
