@@ -24,7 +24,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench.reference import adapt as RA
-from bench.reference import lm as RL
 
 #: Leaves whose reference value is under this share of the median leaf of
 #: their kind are left out of a leaf comparison (round-off moves them).
@@ -98,7 +97,9 @@ def _gaps(ref, other, served):
 def serve_readings(params, session, sample, pad_to: int,
                    control: bool = False) -> dict:
     """Widest served-token gap over the sample (and, with ``control``, the
-    widest gap of the tokens the fp8 reference ranks first)."""
+    widest gap of the tokens the fp8 reference ranks first). The
+    reference is the configuration's own (``session.reference``)."""
+    reference = session.reference
     widest, widest_ctrl, n_tok = 0.0, 0.0, 0
     for s in sample:
         prompt = np.asarray(s.req.prompt, np.int32)
@@ -108,9 +109,9 @@ def serve_readings(params, session, sample, pad_to: int,
         toks[: seq.size] = seq
         a, b = _adapters_of(session, s.planned.tenant)
         lo, hi = prompt.size - 1, prompt.size - 1 + served.size
-        ref = RL.sequence_logits(params, session.cfg, toks, a, b)
-        ctrl = (RL.sequence_logits(params, session.cfg, toks, a, b, "fp8")
-                if control else ref)
+        ref = reference.sequence_logits(params, session.cfg, toks, a, b)
+        ctrl = (reference.sequence_logits(params, session.cfg, toks, a, b,
+                                          "fp8") if control else ref)
         g, gc = _gaps(ref[lo:hi], ctrl[lo:hi], jnp.asarray(served))
         widest = max(widest, float(jnp.max(g)))
         widest_ctrl = max(widest_ctrl, float(jnp.max(gc)))
@@ -160,7 +161,8 @@ def job_reference(params, session, precision: str = "f32",
     ft = session.ft
     tokens = np.concatenate([np.concatenate([b[0] for b in j.batches]) for j in jobs])
     labels = np.stack([np.concatenate([b[1] for b in j.batches]) for j in jobs])
-    h, xs = RA.features(params, session.cfg, tokens, precision)
+    h, xs = RA.features(session.reference, params, session.cfg, tokens,
+                        precision)
     n = int(ft["samples"])
     h = h.reshape((len(jobs), n) + h.shape[1:])
     xs = xs.reshape((len(jobs), n) + xs.shape[1:])
@@ -174,8 +176,9 @@ def job_reference(params, session, precision: str = "f32",
     d = session.mcfg
     ad0 = RA.init_adapters(session.adapt_key, len(jobs), d.n_layers, d.d_model,
                            session.rank)
-    steps = RA.run_steps(params, session.cfg, h, xs, jnp.asarray(labels), plan,
-                         ad0, float(session.cfg["adapt_lr"]), 3, precision)
+    steps = RA.run_steps(session.reference, params, session.cfg, h, xs,
+                         jnp.asarray(labels), plan, ad0,
+                         float(session.cfg["adapt_lr"]), 3, precision)
     return [ad0] + steps
 
 

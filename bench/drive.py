@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench import gen
+from bench import model as M
 from bench import weights as W
 
 
@@ -70,24 +71,6 @@ class Served:
         return self.last_at is not None
 
 
-def model_config(cfg: dict):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.models.config import ModelConfig
-
-    return ModelConfig(
-        name=cfg["name"], family="dense",
-        n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        pattern=("attn",), rope_pct=cfg["partial_rotary_factor"],
-        rope_theta=float(cfg["rope_theta"]), ffn_activation=cfg["hidden_act"],
-        ffn_gated=cfg["ffn_gated"], norm_type=cfg["norm_type"],
-        norm_eps=cfg["layer_norm_eps"],
-        tie_embeddings=cfg["tie_word_embeddings"], dtype=cfg["torch_dtype"],
-    )
-
-
 def runtime_options(opts: dict) -> dict:
     """A mix's ``runtime`` part as ``SessionRuntime`` keywords: all are
     passed through, and ``mesh`` (``{"shape": [...], "axes": [...]}``) is
@@ -103,7 +86,9 @@ def runtime_options(opts: dict) -> dict:
 
 class Session:
     """One cell's system under test: weights and served adapters from the
-    seed, the runtime, its scheduler, and the traffic streams."""
+    seed, the runtime, its scheduler, and the traffic streams. ``model``
+    and ``reference`` are the modules the configuration names
+    (``bench/model.py``)."""
 
     def __init__(self, cfg: dict, mix: dict, seed: int, seconds: float, *,
                  root: Optional[str] = None):
@@ -112,7 +97,9 @@ class Session:
 
         root = root or os.path.dirname(gen.HERE)
         self.cfg, self.seed = cfg, seed
-        self.mcfg = model_config(cfg)
+        self.model = M.model(cfg, root)
+        self.reference = M.reference(cfg, root)
+        self.mcfg = self.model.program_config(cfg)
         self.rt_seed = seed % 2**31
         self.serve = mix.get("serve")
         self.ft = mix.get("finetune")
@@ -128,7 +115,7 @@ class Session:
             gen.driver(self.ft["driver"], root).stream(self.ft, vocab=vocab, seed=seed)
             if self.ft else None
         )
-        self.params = W.make_params(cfg, seed)
+        self.params = self.model.make_params(cfg, seed)
         jax.block_until_ready(self.params)
         self.served_names = list(self.stream.tenants) if self.stream else []
         n_served = len(self.served_names)

@@ -2,7 +2,9 @@
 
 The program's adapt reads block inputs and the base hidden state from the
 skip cache that ingest filled. This reference recomputes them with the
-backbone forward (``reference.lm.hidden``) and then takes the same steps:
+backbone forward of the configuration's own reference (``ref``, the
+module ``bench/model.py`` finds: its ``hidden``, ``logits`` and
+``cfg_items``) and then takes the same steps:
 
     loss_t(A, B) = mean over tenant t's rows and tokens of
                    CE(LayerNorm_f(h + sum_l x_l A_l B_l) Head^T, label)
@@ -26,7 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.reference import lm
+from bench.reference.common import skip_term
 
 B1, B2, EPS = 0.9, 0.999, 1e-8
 
@@ -54,38 +56,39 @@ def init_adapters(key, n: int, L: int, D: int, R: int) -> dict:
     return {"A": a, "B": jnp.zeros((n, L, R, D), jnp.float32)}
 
 
-@functools.partial(jax.jit, static_argnames=("cfg_items", "precision"))
-def _features(params, tokens, *, cfg_items, precision):
+@functools.partial(jax.jit, static_argnames=("ref", "cfg_items", "precision"))
+def _features(params, tokens, *, ref, cfg_items, precision):
     """Per sample: (final hidden (S, D), block inputs (L, S, D))."""
     cfg = dict(cfg_items)
     return jax.lax.map(
-        lambda t: lm.hidden(params, cfg, t, precision), tokens, batch_size=8
+        lambda t: ref.hidden(params, cfg, t, precision), tokens, batch_size=8
     )
 
 
-def features(params, cfg: dict, tokens, precision: str = "f32"):
+def features(ref, params, cfg: dict, tokens, precision: str = "f32"):
     """(h (N, S, D), xs (N, L, S, D)) for N samples of S tokens."""
     return _features(
-        params, jnp.asarray(tokens), cfg_items=lm.cfg_items(cfg),
+        params, jnp.asarray(tokens), ref=ref, cfg_items=ref.cfg_items(cfg),
         precision=precision,
     )
 
 
-def _tenant_loss(params, cfg, precision, a, b, h, xs, labels):
+def _tenant_loss(ref, params, cfg, precision, a, b, h, xs, labels):
     """Mean CE of one tenant's rows: h (n, S, D), xs (n, L, S, D)."""
-    skip = jax.vmap(lambda x: lm.skip_term(x, a, b, precision))(xs)
-    lg = lm.logits(params, cfg, h + skip, precision)
+    skip = jax.vmap(lambda x: skip_term(x, a, b, precision))(xs)
+    lg = ref.logits(params, cfg, h + skip, precision)
     lp = jax.nn.log_softmax(lg, -1)
     ll = jnp.take_along_axis(lp, labels[..., None], -1)[..., 0]
     return -jnp.mean(ll)
 
 
-@functools.partial(jax.jit, static_argnames=("cfg_items", "precision"))
-def _step(params, ad, mu, nu, count, h, xs, labels, lr, *, cfg_items, precision):
+@functools.partial(jax.jit, static_argnames=("ref", "cfg_items", "precision"))
+def _step(params, ad, mu, nu, count, h, xs, labels, lr, *, ref, cfg_items,
+          precision):
     """One AdamW step for every tenant: h (T, n, S, D), xs (T, n, L, S, D),
     labels (T, n, S). Returns (adapters, mu, nu, losses (T,), grads)."""
     cfg = dict(cfg_items)
-    loss = functools.partial(_tenant_loss, params, cfg, precision)
+    loss = functools.partial(_tenant_loss, ref, params, cfg, precision)
 
     def per_tenant(a, b, ht, xt, lt):
         return jax.value_and_grad(loss, argnums=(0, 1))(a, b, ht, xt, lt)
@@ -103,7 +106,7 @@ def _step(params, ad, mu, nu, count, h, xs, labels, lr, *, cfg_items, precision)
     return ad, mu, nu, losses, g
 
 
-def run_steps(params, cfg: dict, h, xs, labels, plan, ad0, lr: float,
+def run_steps(ref, params, cfg: dict, h, xs, labels, plan, ad0, lr: float,
               n_steps: int, precision: str = "f32"):
     """``n_steps`` steps from ``ad0``. h (T, n, S, D), xs (T, n, L, S, D),
     labels (T, n, S) per tenant; ``plan`` (steps, T, bpt) row ids.
@@ -117,8 +120,8 @@ def run_steps(params, cfg: dict, h, xs, labels, plan, ad0, lr: float,
         idx = jnp.asarray(plan[s])
         ad, mu, nu, losses, g = _step(
             params, ad, mu, nu, jnp.float32(s), h[rows, idx], xs[rows, idx],
-            labels[rows, idx], jnp.float32(lr), cfg_items=lm.cfg_items(cfg),
-            precision=precision,
+            labels[rows, idx], jnp.float32(lr), ref=ref,
+            cfg_items=ref.cfg_items(cfg), precision=precision,
         )
         out.append({"losses": losses, "adapters": ad, "mu": mu, "nu": nu,
                     "grads": g})

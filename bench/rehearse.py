@@ -54,8 +54,7 @@ def main(argv=None) -> int:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from bench import drive
-    from bench import weights as W
+    from bench import model as M
     from bench.run import load_cell
     from repro.core import fleet_finetune as FF
     from repro.core import lm_skiplora as SL
@@ -67,10 +66,11 @@ def main(argv=None) -> int:
     jax.config.update("jax_enable_compilation_cache", False)
     cell = load_cell(ROOT, args.workload)
     cfg, mix = cell["config"], cell["mix"]
-    mcfg = drive.model_config(cfg)
+    model = M.model(cfg, ROOT)
+    mcfg = model.program_config(cfg)
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     one = SingleDeviceSharding(topo.devices[0])
-    params = _abstract(jax.eval_shape(lambda: W.make_params(cfg, 0)), one)
+    params = _abstract(jax.eval_shape(lambda: model.make_params(cfg, 0)), one)
     rank = int(cfg["skip_lora_rank"])
     L, D = mcfg.n_layers, mcfg.d_model
     f32 = jnp.float32

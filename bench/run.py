@@ -3,9 +3,11 @@
     python bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Runs one cell of ``BENCHMARK.json`` on the chip it is started on: builds
-the session the cell's configuration (``bench/configs/<config>.json``) and
-traffic mix (``bench/traffic/<traffic>.json``, whose parts name their
-drivers, ``bench/traffic/<driver>.py``) describe, warms up every
+the session the cell's configuration (``bench/configs/<config>.json``,
+whose ``model`` names ``bench/models/<model>.py`` and its reference
+``bench/reference/<model>.py``; ``bench/model.py``) and traffic mix
+(``bench/traffic/<traffic>.json``, whose parts name their drivers,
+``bench/traffic/<driver>.py``) describe, warms up every
 program the window runs, measures for ``--seconds``, then checks what the
 timed path produced against the plain reference (``bench/check.py``,
 limits in ``bench/limits/<cell>.json``). With ``--trace 0`` the result

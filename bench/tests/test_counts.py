@@ -6,6 +6,7 @@ from bench import counts as C
 from bench import peaks as P
 
 TINY = {
+    "model": "dense",
     "num_hidden_layers": 2, "hidden_size": 8, "num_attention_heads": 2,
     "num_key_value_heads": 2, "intermediate_size": 16, "vocab_size": 10,
     "ffn_gated": True, "skip_lora_rank": 2,

@@ -12,9 +12,8 @@ import pytest
 
 from bench import check as C
 from bench import drive
+from bench import model as M
 from bench import weights as W
-from bench.reference import adapt as RA
-from bench.reference import lm as RL
 from bench.tests.tiny import TINY_CONFIG
 
 import json
@@ -36,8 +35,9 @@ def test_serve_logits_match_program(base):
     from repro.models.lm import lm_forward, readout
 
     cfg = tiny_cfg(base)
-    mcfg = drive.model_config(cfg)
-    params = W.make_params(cfg, 3)
+    model, reference = M.model(cfg), M.reference(cfg)
+    mcfg = model.program_config(cfg)
+    params = model.make_params(cfg, 3)
     ad = W.make_adapters(cfg, 3, 2)
     a, b = ad["A"][1], ad["B"][1]
     tokens = jax.random.randint(jax.random.key(0), (12,), 0, cfg["vocab_size"])
@@ -45,11 +45,11 @@ def test_serve_logits_match_program(base):
         out = lm_forward(params, mcfg, tokens[None], mode="train",
                          adapters=SL.adapters_to_stack({"A": a, "B": b}, mcfg))
         prog = readout(params, mcfg, out["h"])[0]
-    ref = RL.sequence_logits(params, cfg, tokens, a, b)
+    ref = reference.sequence_logits(params, cfg, tokens, a, b)
     scale = float(jnp.max(jnp.abs(ref)))
     assert float(jnp.max(jnp.abs(prog - ref))) <= 1e-4 * scale
     # The adapters move the logits: the comparison sees the skip term.
-    base_ref = RL.sequence_logits(params, cfg, tokens, 0 * a, 0 * b)
+    base_ref = reference.sequence_logits(params, cfg, tokens, 0 * a, 0 * b)
     assert float(jnp.max(jnp.abs(base_ref - ref))) > 1e-2 * scale
 
 
